@@ -20,6 +20,8 @@ import traceback
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import (bench_kernels, bench_models, bench_payload,
                    bench_pipeline, bench_privacy, bench_protocols,
                    bench_roofline, bench_sampling, bench_scalability,
@@ -48,6 +50,7 @@ def main(argv=None) -> None:
                              f"{sorted(unknown)}; "
                              f"available: {[n for n, _ in modules]}")
         modules = [(n, m) for n, m in modules if n in wanted]
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, mod in modules:

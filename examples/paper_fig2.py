@@ -10,12 +10,14 @@ import sys
 sys.path.insert(0, ".")  # allow `benchmarks` import when run from repo root
 
 from benchmarks.bench_protocols import run
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     res = run(quick=args.quick)
     print("\n=== final accuracies ===")
     for k, v in sorted(res.items()):

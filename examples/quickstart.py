@@ -19,10 +19,12 @@ import jax.numpy as jnp
 from repro.channel import ChannelConfig
 from repro.core.protocols import FederatedConfig, FederatedTrainer
 from repro.data import partition_iid, synthetic_images
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.cnn import CNN
 
 
 def main():
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     x, y = synthetic_images(key, 3500)
     dev_x, dev_y = partition_iid(x[:2500], y[:2500], 5, 500, 10)

@@ -38,11 +38,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:  # jax >= 0.6 graduated shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..channel import ChannelConfig
 from ..channel.payload import CodecSpec, LinkConfig, parse_codec
@@ -529,13 +525,13 @@ class FederatedTrainer:
         self._local_train = jax.jit(shard_map(
             vmapped, mesh=self.mesh,
             in_specs=(dev, dev, dev, dev, dev, rep),
-            out_specs=(dev, dev, dev, dev), check_rep=False))
+            out_specs=(dev, dev, dev, dev), check_vma=False))
         self._weighted_avg = jax.jit(shard_map(
             weighted_avg_psum, mesh=self.mesh, in_specs=(dev, dev),
-            out_specs=rep, check_rep=False))
+            out_specs=rep, check_vma=False))
         self._gout_update = jax.jit(shard_map(
             gout_update_psum, mesh=self.mesh, in_specs=(dev, dev, dev),
-            out_specs=rep, check_rep=False))
+            out_specs=rep, check_vma=False))
 
     # ------------------------------------------------------------------
     def collect_seeds(self, dev_x, dev_y, key):
@@ -907,6 +903,7 @@ def make_grid_round_step(model_apply, *, protocol: str, num_devices: int,
                          local_train_fn: Optional[Callable] = None,
                          weighted_avg_fn: Optional[Callable] = None,
                          gout_update_fn: Optional[Callable] = None,
+                         grid_shard: Optional[Callable] = None,
                          codec: str = "identity",
                          cohort_size: Optional[int] = None,
                          arch_groups: Optional[list] = None):
@@ -955,7 +952,11 @@ def make_grid_round_step(model_apply, *, protocol: str, num_devices: int,
     ``local_train_fn``/``weighted_avg_fn``/``gout_update_fn`` default to
     the vmapped single-chip forms; the sweep engine substitutes
     shard_mapped variants (device axis on the "data" mesh) for
-    ``shard_devices`` grids.
+    ``shard_devices`` grids.  ``grid_shard``, when given, wraps the
+    G-vmapped eq. 5 conversion and evaluation (every argument and output
+    G-leading) so that each shard of a ``"grid"`` mesh axis runs them for
+    its own points only; left to the compiler, they are all-gathered and
+    run at the full grid width on every chip.
 
     ``codec`` is the link codec *family* of this program (a structural
     axis: the sweep engine compiles one program per (protocol, codec)
@@ -1035,6 +1036,8 @@ def make_grid_round_step(model_apply, *, protocol: str, num_devices: int,
                         .astype(jnp.float32))
 
     acc_fn = jax.vmap(acc_one)
+    if grid_shard is not None:
+        conv_fn, acc_fn = grid_shard(conv_fn), grid_shard(acc_fn)
 
     def flatten_grid(tree):
         return jnp.concatenate(

@@ -6,6 +6,9 @@ oracle in ref.py and a jit wrapper in ops.py.
   flash_attention — block-tiled online-softmax attention (prefill path)
   ssd_scan        — Mamba2 SSD chunked scan (state-space duality)
 
-On CPU (this container) kernels run with interpret=True; on TPU the same
-pallas_call lowers to Mosaic.
+Each kernel picks its mode from ``runtime.default_interpret``: on the CPU
+(the test suite sets ``JAX_PLATFORMS=cpu``) it runs in the Pallas
+interpreter; on a TPU the same pallas_call compiles to a Mosaic kernel
+(``tpu_custom_call`` in the HLO).  ``python chip_smoke.py`` at the repo
+root checks the compiled path on one chip.
 """

@@ -53,9 +53,12 @@ def _distill_kernel(z_ref, y_ref, g_ref, beta_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def distill_loss_pallas(logits, labels, g_rows, beta, *,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """logits: (N, C); labels: (N,) int32; g_rows: (N, C) KD target rows;
-    beta: scalar. Returns per-sample losses (N,)."""
+    beta: scalar. Returns per-sample losses (N,).  ``interpret=None``
+    resolves per backend (:func:`~.runtime.default_interpret`)."""
+    if interpret is None:
+        interpret = _default_interpret()
     n, c = logits.shape
     rb = min(ROW_BLOCK, n)
     if n % rb:

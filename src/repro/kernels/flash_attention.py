@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .runtime import default_interpret as _default_interpret
+
 Q_BLOCK = 256
 KV_BLOCK = 256
 NEG_INF = -1e30
@@ -67,11 +69,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit,
                    static_argnames=("window", "interpret", "blk_q", "blk_k"))
-def flash_attention_pallas(q, k, v, *, window=None, interpret: bool = True,
+def flash_attention_pallas(q, k, v, *, window=None,
+                           interpret: bool | None = None,
                            blk_q: int = Q_BLOCK, blk_k: int = KV_BLOCK):
     """q, k, v: (BH, S, d) — heads pre-flattened into the batch dim,
     grouped-query repetition done by the caller.  Causal.  Returns
-    (BH, S, dv)."""
+    (BH, S, dv).  ``interpret=None`` resolves per backend
+    (:func:`~.runtime.default_interpret`)."""
+    if interpret is None:
+        interpret = _default_interpret()
     bh, s, d = q.shape
     dv = v.shape[-1]
     blk_q = min(blk_q, s)

@@ -1,7 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only); on
-TPU backends the same pallas_call lowers to Mosaic.
+Every kernel resolves ``interpret`` per backend
+(:func:`.runtime.default_interpret`): on a TPU the pallas_call lowers to a
+compiled Mosaic kernel, on the CPU (the test suite runs with
+``JAX_PLATFORMS=cpu``) it runs in the Pallas interpreter.
 """
 from __future__ import annotations
 
@@ -10,7 +12,6 @@ import jax.numpy as jnp
 from .distill_loss import distill_loss_pallas
 from .flash_attention import flash_attention_pallas
 from .mixup_kernel import mixup_pallas
-from .runtime import default_interpret as _interpret
 from .ssd_scan import ssd_scan_pallas
 
 
@@ -21,7 +22,7 @@ def mixup(a, b, lam: float):
     flat_b = b.reshape(n, -1)
     la = jnp.full((n,), lam, jnp.float32)
     lb = jnp.full((n,), 1.0 - lam, jnp.float32)
-    out = mixup_pallas(flat_a, flat_b, la, lb, interpret=_interpret())
+    out = mixup_pallas(flat_a, flat_b, la, lb)
     return out.reshape(a.shape)
 
 
@@ -33,26 +34,23 @@ def inverse_mixup_pair(mixed_a, mixed_b, lam: float):
     fb = mixed_b.reshape(n, -1)
     l1 = jnp.full((n,), lam_hat, jnp.float32)
     l2 = 1.0 - l1
-    s1 = mixup_pallas(fa, fb, l1, l2, interpret=_interpret())
-    s2 = mixup_pallas(fa, fb, l2, l1, interpret=_interpret())
+    s1 = mixup_pallas(fa, fb, l1, l2)
+    s2 = mixup_pallas(fa, fb, l2, l1)
     return s1.reshape(mixed_a.shape), s2.reshape(mixed_a.shape)
 
 
 def distill_loss(logits, labels, gout, beta: float):
     """Mean of eq. (3) over a batch; gout: (C, C) KD table."""
     g_rows = gout[labels]
-    per = distill_loss_pallas(logits, labels, g_rows, beta,
-                              interpret=_interpret())
+    per = distill_loss_pallas(logits, labels, g_rows, beta)
     return jnp.mean(per)
 
 
 def flash_attention(q, k, v, *, window=None):
     """Causal attention, (BH, S, d) layout (see kernels/flash_attention)."""
-    return flash_attention_pallas(q, k, v, window=window,
-                                  interpret=_interpret())
+    return flash_attention_pallas(q, k, v, window=window)
 
 
 def ssd_scan(xdt, Bh, Ch, dA, *, chunk: int = 64):
     """Mamba2 SSD over (BH, S, ·) tensors."""
-    return ssd_scan_pallas(xdt, Bh, Ch, dA, chunk=chunk,
-                           interpret=_interpret())
+    return ssd_scan_pallas(xdt, Bh, Ch, dA, chunk=chunk)

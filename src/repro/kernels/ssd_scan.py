@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .runtime import default_interpret as _default_interpret
+
 
 def _ssd_kernel(x_ref, b_ref, c_ref, da_ref, y_ref, state_scr, *, chunk):
     ci = pl.program_id(1)
@@ -64,10 +66,13 @@ def _ssd_kernel(x_ref, b_ref, c_ref, da_ref, y_ref, state_scr, *, chunk):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_pallas(xdt, Bh, Ch, dA, *, chunk: int = 64,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """xdt: (BH, S, P) = x * dt; Bh/Ch: (BH, S, N); dA: (BH, S) (<= 0).
     Returns y: (BH, S, P).  Per-(batch, head) layout — the caller
-    flattens (B, H) and broadcasts groups."""
+    flattens (B, H) and broadcasts groups.  ``interpret=None`` resolves
+    per backend (:func:`~.runtime.default_interpret`)."""
+    if interpret is None:
+        interpret = _default_interpret()
     bh, s, p = xdt.shape
     n = Bh.shape[-1]
     chunk = min(chunk, s)

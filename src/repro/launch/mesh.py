@@ -11,6 +11,7 @@ state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants used by the roofline analysis
 PEAK_FLOPS_BF16 = 197e12   # per chip
@@ -18,15 +19,24 @@ HBM_BW = 819e9             # bytes/s per chip
 ICI_BW = 50e9              # bytes/s per link
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the compiler propagates
+    shardings, as every path here assumes (``jax.make_mesh`` defaults to
+    ``Explicit`` axes, under which plain indexing of a sharded array
+    must name its output sharding)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke tests (same axis names)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_device_mesh(num_devices: int, shards: int | None = None):
@@ -46,7 +56,7 @@ def make_device_mesh(num_devices: int, shards: int | None = None):
     if num_devices % shards:
         raise ValueError(f"device population {num_devices} not divisible "
                          f"by {shards} mesh shards")
-    return jax.make_mesh((shards,), ("data",))
+    return _auto_mesh((shards,), ("data",))
 
 
 def _largest_divisor(n: int, limit: int) -> int:
@@ -102,7 +112,7 @@ def make_grid_mesh(grid_size: int, num_devices: int,
     contract as :func:`make_device_mesh`.
     """
     gs, ds = grid_mesh_shape(grid_size, num_devices, shape)
-    return jax.make_mesh((gs, ds), ("grid", "data"))
+    return _auto_mesh((gs, ds), ("grid", "data"))
 
 
 def data_axes(mesh) -> tuple:

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data import synthetic_tokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models.transformer import count_params, init_params
 
@@ -127,6 +128,7 @@ def main():
     ap.add_argument("--full", action="store_true",
                     help="use the full (non-smoke) config")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.classifier is not None:
         serve_classifier(args.classifier, args.task, args.batch)
         return

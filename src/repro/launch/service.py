@@ -58,6 +58,7 @@ from repro.core.protocols import (FederatedConfig, FederatedTrainer,
                                   summarize_seeds)
 from repro.core.sampling import ChurnConfig
 from repro.core.state import RoundState
+from repro.launch.compile_cache import enable_compile_cache
 
 __all__ = ["ChurnConfig", "FederatedService", "InferenceEndpoint"]
 
@@ -409,7 +410,7 @@ def _tail(records):
                                "uplink_ok")} for r in records]
 
 
-def main(argv=None) -> int:
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description="continuous federated service smoke")
     ap.add_argument("--rounds", type=int, default=4)
@@ -433,7 +434,12 @@ def main(argv=None) -> int:
                     help="restore the halfway checkpoint into a fresh "
                          "service, re-run the tail, and require "
                          "identical per-round records")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> int:
+    args = _parser().parse_args(argv)
+    enable_compile_cache()
     if args.ckpt_dir is None:
         args.ckpt_dir = tempfile.mkdtemp(prefix="fedsvc_")
 

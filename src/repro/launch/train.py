@@ -30,6 +30,7 @@ from repro.channel import ChannelConfig
 from repro.configs import get_config
 from repro.core.protocols import FederatedConfig, FederatedTrainer
 from repro.data import synthetic_tokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import (make_favg_step, make_fd_sync_step,
                                 make_local_train_step)
 from repro.models.cnn import CNN
@@ -146,6 +147,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--ckpt-dir", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "paper":
         run_paper(args)
     else:

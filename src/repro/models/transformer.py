@@ -29,27 +29,6 @@ from .shardhooks import constrain
 REMAT_POLICY = jax.checkpoint_policies.nothing_saveable
 
 
-# optimization_barrier has neither a JVP nor a batching rule in this jax
-# version, which breaks jax.grad / jax.vmap through the scanned blocks.
-# The barrier only needs to pin the *primal* graph, so register identity
-# rules for both transforms (guarded: future jax may ship its own, or may
-# move the private primitive — in which case it likely has the rules too).
-try:
-    from jax._src.lax import lax as _lax_internal  # noqa: E402
-    from jax.interpreters import ad as _ad, batching as _batching  # noqa: E402
-
-    _obar_p = _lax_internal.optimization_barrier_p
-    if _obar_p not in _batching.primitive_batchers:
-        _batching.primitive_batchers[_obar_p] = (
-            lambda args, dims: (_obar_p.bind(*args), dims))
-    if _obar_p not in _ad.primitive_jvps:
-        _ad.primitive_jvps[_obar_p] = (
-            lambda primals, tangents: (_obar_p.bind(*primals),
-                                       list(tangents)))
-except (ImportError, AttributeError):
-    pass
-
-
 def _opt_barrier(x):
     return jax.lax.optimization_barrier(x)
 

@@ -54,12 +54,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6 graduated shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 from ..channel import round_slot_plan
 from ..core.privacy import GaussianAccountant, gaussian_epsilon
@@ -493,14 +489,21 @@ class _ProtocolProgram:
                 grid_lt, mesh=mesh,
                 in_specs=(gdev, ddev, ddev, gdev, gdev, rep, gcfg, gcfg,
                           gcfg),
-                out_specs=(gdev, gdev, gdev, gdev), check_rep=False)
+                out_specs=(gdev, gdev, gdev, gdev), check_vma=False)
             fns["weighted_avg_fn"] = shard_map(
                 jax.vmap(weighted_avg_psum), mesh=mesh,
-                in_specs=(gdev, gdev), out_specs=gcfg, check_rep=False)
+                in_specs=(gdev, gdev), out_specs=gcfg, check_vma=False)
             fns["gout_update_fn"] = shard_map(
                 jax.vmap(gout_update_psum), mesh=mesh,
                 in_specs=(gdev, gdev, gdev), out_specs=gcfg,
-                check_rep=False)
+                check_vma=False)
+            if grid_axis:
+                # conversion and evaluation: each grid shard runs its own
+                # points, replicated over "data" (the compiler would
+                # otherwise gather them to full grid width on every chip)
+                fns["grid_shard"] = lambda f: shard_map(
+                    f, mesh=mesh, in_specs=gcfg, out_specs=gcfg,
+                    check_vma=False)
 
         round_step = make_grid_round_step(
             model.apply, protocol=proto, num_devices=D,

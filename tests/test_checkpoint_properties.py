@@ -22,8 +22,10 @@ def leaves(draw):
     shape = draw(_SHAPES)
     dtype = draw(_DTYPES)
     n = int(np.prod(shape, dtype=np.int64)) if shape else 1
+    # each dtype draws only values it can hold (NumPy 2 rejects -1 as uint)
+    lo = 0 if np.issubdtype(dtype, np.unsignedinteger) else -1000
     vals = draw(st.lists(
-        st.integers(-1000, 1000), min_size=n, max_size=n))
+        st.integers(lo, 1000), min_size=n, max_size=n))
     return np.asarray(vals, dtype=dtype).reshape(shape)
 
 

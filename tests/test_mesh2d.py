@@ -3,7 +3,7 @@
 The sweep engine lays grid points along the ``"grid"`` axis and each
 point's federated device axis along ``"data"`` (docs/pod_scale.md).
 Grid points share no collectives — the psums stay over ``"data"`` — so
-grid-axis sharding must be *bitwise* the vmapped program, while
+grid-axis sharding must be *bitwise* each point's vmapped program, while
 device-axis sharding keeps the same reduction widths as the existing
 1-D ``shard_devices`` path and must match it to 1e-6.
 
@@ -125,22 +125,39 @@ def test_runner_clamps_oversized_mesh_request(data):
 @pytest.mark.multichip
 def test_grid_axis_sharding_is_bitwise_vmapped(data):
     """Grid-axis-only sharding (2, 1): no collective anywhere touches a
-    different operand set than the vmapped program, so the histories
-    must match bitwise, not just to tolerance."""
+    different operand set than the vmapped program, so each point's
+    history must match that point swept alone bitwise, not just to
+    tolerance.  Alone, not in a 2-point vmap: each shard runs a 1-point
+    program, and the compiler may round a 2-wide vmap differently."""
     dev_x, dev_y, tx, ty = data
     grid_m = make_grid(_base(), CH, eta=(0.01, 0.02))
     runner = SweepRunner(CNN(), grid_m, dev_x, dev_y, tx, ty,
                          options=ProgramOptions(mesh_shape=(2, 1)))
     assert all(p.mesh_shape == (2, 1) for _, _, p in runner._programs)
     res_m = runner.run()
-    grid_v = make_grid(_base(), CH, eta=(0.01, 0.02))
-    res_v = run_sweep(CNN(), grid_v, dev_x, dev_y, tx, ty)
-    for g in range(2):
-        hm, hv = res_m.history(g), res_v.history(g)
+    for g, eta in enumerate((0.01, 0.02)):
+        grid_v = make_grid(_base(), CH, eta=(eta,))
+        res_v = run_sweep(CNN(), grid_v, dev_x, dev_y, tx, ty)
+        hm, hv = res_m.history(g), res_v.history(0)
         np.testing.assert_array_equal(hm["acc"], hv["acc"])
         np.testing.assert_array_equal(hm["loss"], hv["loss"])
         assert hm["uplink_ok"] == hv["uplink_ok"]
         assert hm["converged_round"] == hv["converged_round"]
+
+
+@pytest.mark.multichip
+def test_grid_axis_sharding_gathers_nothing(data):
+    """Each grid shard converts and evaluates its own points: the compiled
+    (2, 1) program holds no all-gather (left to sharding propagation, the
+    grid-vmapped conversion and evaluation were gathered to full grid
+    width on every chip, which the TPU rounds differently)."""
+    dev_x, dev_y, tx, ty = data
+    grid = make_grid(_base(max_rounds=2), CH, eta=(0.01, 0.02))
+    runner = SweepRunner(CNN(), grid, dev_x, dev_y, tx, ty,
+                         options=ProgramOptions(mesh_shape=(2, 1)))
+    (_, _, prog), = runner._programs
+    hlo = prog._step_fn.lower(prog._state0, prog._xs).compile().as_text()
+    assert "all-gather" not in hlo
 
 
 @pytest.mark.multichip

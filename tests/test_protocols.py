@@ -230,27 +230,30 @@ GOLDEN_CH = ChannelConfig(num_devices=4, p_up_dbm=40.0)
 # the snippet in docs/sharded_round_loop.md §Regression goldens.
 # mix2fld re-recorded when the segment/sort cycle search replaced the
 # budgeted DFS (higher cycle yield changes the round-1 inverse set).
+# All five re-recorded on JAX 0.9.0, whose default threefry PRNG
+# partitioning (jax_threefry_partitionable=True) draws different streams
+# than the 0.4.37 default the earlier goldens were recorded under.
 GOLDEN = {
     "fl": dict(
-        acc=[0.075, 0.125, 0.285],
-        loss=[2.324292, 2.29544, 2.267828],
-        latency_s=[0.062, 0.06, 0.062]),
+        acc=[0.225, 0.225, 0.19],
+        loss=[2.313039, 2.278673, 2.267214],
+        latency_s=[0.061, 0.063, 0.061]),
     "fd": dict(
-        acc=[0.11, 0.105, 0.14],
-        loss=[2.324292, 2.31746, 2.294407],
+        acc=[0.13, 0.18, 0.1],
+        loss=[2.313039, 2.311103, 2.290004],
         latency_s=[0.002, 0.002, 0.002]),
     "fld": dict(
-        acc=[0.12, 0.12, 0.13],
-        loss=[2.324292, 2.32959, 2.335337],
-        latency_s=[0.027, 0.021, 0.022]),
+        acc=[0.065, 0.065, 0.065],
+        loss=[2.313039, 2.344419, 2.341425],
+        latency_s=[0.026, 0.022, 0.022]),
     "mixfld": dict(
-        acc=[0.105, 0.095, 0.095],
-        loss=[2.324292, 2.37006, 2.356361],
-        latency_s=[0.027, 0.021, 0.022]),
+        acc=[0.09, 0.16, 0.175],
+        loss=[2.313039, 2.309267, 2.319884],
+        latency_s=[0.026, 0.022, 0.022]),
     "mix2fld": dict(
-        acc=[0.09, 0.215, 0.14],
-        loss=[2.324292, 2.38605, 2.403923],
-        latency_s=[0.027, 0.021, 0.022]),
+        acc=[0.09, 0.115, 0.09],
+        loss=[2.313039, 2.353428, 2.359449],
+        latency_s=[0.026, 0.022, 0.022]),
 }
 
 
