@@ -94,8 +94,9 @@ def compute_outcomes(key, mean_s, deadline_s, n_links: int):
     stage keys off its own fold of the round key, so enabling it never
     perturbs the channel draw stream.
     """
-    t = mean_s * jax.random.exponential(key, (n_links,))
-    return t, t <= deadline_s
+    with jax.named_scope("link_draw"):
+        t = mean_s * jax.random.exponential(key, (n_links,))
+        return t, t <= deadline_s
 
 
 def slowest_ok_time(t, ok, deadline_s):
@@ -150,10 +151,13 @@ def round_trip_traced(key, p_up, up_slots, p_dn, dn_slots, n_links: int,
     function over them batches whole channel regimes into one draw.
     Given equal inputs it consumes the PRNG exactly like ``round_trip``.
     """
-    ku, kd = jax.random.split(key)
-    t_up, ok_up = link_outcomes(ku, p_up, up_slots, n_links, t_max_slots)
-    t_dn, ok_dn = link_outcomes(kd, p_dn, dn_slots, n_links, t_max_slots)
-    latency_s = tau_s * (slowest_ok_slots(t_up, ok_up, t_max_slots) +
-                         slowest_ok_slots(t_dn, ok_dn, t_max_slots))
+    with jax.named_scope("link_draw"):
+        ku, kd = jax.random.split(key)
+        t_up, ok_up = link_outcomes(ku, p_up, up_slots, n_links,
+                                    t_max_slots)
+        t_dn, ok_dn = link_outcomes(kd, p_dn, dn_slots, n_links,
+                                    t_max_slots)
+        latency_s = tau_s * (slowest_ok_slots(t_up, ok_up, t_max_slots) +
+                             slowest_ok_slots(t_dn, ok_dn, t_max_slots))
     return {"up_ok": ok_up, "dn_ok": ok_dn, "t_up": t_up, "t_dn": t_dn,
             "latency_s": latency_s}

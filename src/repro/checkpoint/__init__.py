@@ -126,7 +126,10 @@ def save(ckpt_dir: str, step: int, tree, *, meta: dict | None = None,
     target = os.path.join(ckpt_dir, _step_name(step))
     tmp = tempfile.mkdtemp(prefix=_TMP_PREFIX, dir=ckpt_dir)
     try:
-        arrays = {f"a{i}": np.asarray(leaf) for i, leaf in enumerate(leaves)}
+        with jax.profiler.TraceAnnotation("checkpoint.d2h") as span:
+            arrays = {f"a{i}": np.asarray(leaf)
+                      for i, leaf in enumerate(leaves)}
+            span.set_metadata(bytes=sum(a.nbytes for a in arrays.values()))
         np.savez(os.path.join(tmp, "arrays.npz"), **arrays)
         with open(os.path.join(tmp, "manifest.json"), "w") as f:
             json.dump({"step": step, "paths": paths, "meta": meta or {}}, f)

@@ -61,8 +61,8 @@ def output_to_model(model_apply, params, seeds_x, seeds_y, gout,
         return _conversion_step(model_apply, seeds_x, seeds_y, gout, n,
                                 batch, eta, beta, carry, k)
 
-    params, losses = jax.lax.scan(step, params, jax.random.split(key, iters))
-    return params, losses
+    with jax.named_scope("convert"):
+        return jax.lax.scan(step, params, jax.random.split(key, iters))
 
 
 def output_to_model_steps(model_apply, params, seeds_x, seeds_y, gout,
@@ -89,6 +89,5 @@ def output_to_model_steps(model_apply, params, seeds_x, seeds_y, gout,
         return params, jnp.where(live, l, 0.0)
 
     k_max = step_keys.shape[0]
-    params, losses = jax.lax.scan(
-        step, params, (step_keys, jnp.arange(k_max)))
-    return params, losses
+    with jax.named_scope("convert"):
+        return jax.lax.scan(step, params, (step_keys, jnp.arange(k_max)))

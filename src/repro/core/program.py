@@ -31,6 +31,11 @@ with one contract:
 
 The state threaded through every program is the frozen
 :class:`~repro.core.state.RoundState` pytree.
+
+Every round path names its work for JAX's profiler (``docs/tracing.md``):
+host spans (:data:`SPANS`, ``jax.profiler.TraceAnnotation``) that cost
+about a microsecond each when no profiler session is running, and device
+name scopes (:data:`SCOPES`, ``jax.named_scope``) that are metadata only.
 """
 from __future__ import annotations
 
@@ -40,6 +45,24 @@ from typing import Any, Callable, Optional
 import jax
 
 from .state import RoundState
+
+#: Host spans of the round paths.  Each span of a round carries
+#: ``round=p``; within a round they are disjoint, except that
+#: ``checkpoint.d2h`` nests in ``checkpoint``.  No span covers a whole
+#: round.
+SPANS = ("cohort_io", "local_train", "link_draw", "aggregate", "convert",
+         "downlink", "evaluate", "converge", "checkpoint", "checkpoint.d2h",
+         "sweep_group")
+
+#: Device name scopes inside the traced round pieces; the compiled sweep
+#: scan carries the same names as the host loop's programs.
+SCOPES = ("local_train", "link_draw", "aggregate", "convert", "cohort_io")
+
+
+def tree_nbytes(*trees) -> int:
+    """Bytes of every array in ``trees``, from shapes alone (a span's
+    ``bytes`` argument; nothing waits on the device)."""
+    return sum(x.nbytes for x in jax.tree.leaves(trees))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,8 +137,10 @@ class LoopRoundProgram:
         p = state.round + 1
         for q in range(p, p + self.options.pipeline_depth):
             if q not in self._pending:
-                self._pending[q] = (plan, plan.dispatch(
-                    self._round_key(state, q), first_round=q == 1))
+                with jax.profiler.TraceAnnotation("link_draw", round=q,
+                                                  links=plan.n_links):
+                    self._pending[q] = (plan, plan.dispatch(
+                        self._round_key(state, q), first_round=q == 1))
                 self.dispatched += 1
         # drop handles for rounds the loop has already passed (restores)
         for q in list(self._pending):

@@ -57,7 +57,7 @@ from .conversion import output_to_model, output_to_model_steps
 from .losses import fd_loss
 from .outputs import label_averaged_outputs
 from .privacy import GaussianAccountant
-from .program import LoopRoundProgram, ProgramOptions
+from .program import LoopRoundProgram, ProgramOptions, tree_nbytes
 from .sampling import ChurnConfig, SamplerConfig
 from .state import RoundState
 from .seed_prep import (collect_seeds, prepare_seeds,  # noqa: F401
@@ -351,11 +351,12 @@ def make_local_train(apply_fn, num_classes: int, local_iters: int,
             cnt = cnt + jnp.sum(oh, axis=0)
             return (p, out_sum, cnt), l
 
-        init = (params, jnp.zeros((C, C)), jnp.zeros((C,)))
-        (params, out_sum, cnt), losses = jax.lax.scan(
-            step, init, jax.random.split(key, local_iters))
-        favg = out_sum / jnp.maximum(cnt[:, None], 1.0)
-        return params, favg, cnt, jnp.mean(losses)
+        with jax.named_scope("local_train"):
+            init = (params, jnp.zeros((C, C)), jnp.zeros((C,)))
+            (params, out_sum, cnt), losses = jax.lax.scan(
+                step, init, jax.random.split(key, local_iters))
+            favg = out_sum / jnp.maximum(cnt[:, None], 1.0)
+            return params, favg, cnt, jnp.mean(losses)
 
     return local_train
 
@@ -379,34 +380,38 @@ def make_grid_local_train(apply_fn, num_classes: int, local_iters: int,
 
 def weighted_avg(stacked, weights):
     """Weighted model average over the device axis (uplink-success set)."""
-    wsum = jnp.maximum(jnp.sum(weights), 1e-9)
-    return jax.tree.map(
-        lambda s: jnp.tensordot(weights, s, axes=1) / wsum, stacked)
+    with jax.named_scope("aggregate"):
+        wsum = jnp.maximum(jnp.sum(weights), 1e-9)
+        return jax.tree.map(
+            lambda s: jnp.tensordot(weights, s, axes=1) / wsum, stacked)
 
 
 def gout_update(favg, cnt, ok):
     """eq. 2: per-class output average over the successful device set."""
-    cw = ok[:, None] * cnt                  # (D, C) per-class wts
-    num = jnp.einsum("dc,dcm->cm", cw, favg)
-    den = jnp.sum(cw, axis=0)
-    return num / jnp.maximum(den[:, None], 1.0)
+    with jax.named_scope("aggregate"):
+        cw = ok[:, None] * cnt                  # (D, C) per-class wts
+        num = jnp.einsum("dc,dcm->cm", cw, favg)
+        den = jnp.sum(cw, axis=0)
+        return num / jnp.maximum(den[:, None], 1.0)
 
 
 def weighted_avg_psum(stacked, weights):
     """:func:`weighted_avg` for one shard of a shard_mapped device axis:
     partial tensordot over the local slice, psum over "data"."""
-    wsum = jnp.maximum(jax.lax.psum(jnp.sum(weights), "data"), 1e-9)
-    part = jax.tree.map(
-        lambda s: jnp.tensordot(weights, s, axes=1), stacked)
-    return jax.tree.map(lambda t: jax.lax.psum(t, "data") / wsum, part)
+    with jax.named_scope("aggregate"):
+        wsum = jnp.maximum(jax.lax.psum(jnp.sum(weights), "data"), 1e-9)
+        part = jax.tree.map(
+            lambda s: jnp.tensordot(weights, s, axes=1), stacked)
+        return jax.tree.map(lambda t: jax.lax.psum(t, "data") / wsum, part)
 
 
 def gout_update_psum(favg, cnt, ok):
     """:func:`gout_update` with psum collectives over the "data" axis."""
-    cw = ok[:, None] * cnt
-    num = jax.lax.psum(jnp.einsum("dc,dcm->cm", cw, favg), "data")
-    den = jax.lax.psum(jnp.sum(cw, axis=0), "data")
-    return num / jnp.maximum(den[:, None], 1.0)
+    with jax.named_scope("aggregate"):
+        cw = ok[:, None] * cnt
+        num = jax.lax.psum(jnp.einsum("dc,dcm->cm", cw, favg), "data")
+        den = jax.lax.psum(jnp.sum(cw, axis=0), "data")
+        return num / jnp.maximum(den[:, None], 1.0)
 
 
 # Round-1 seed collection lives in core.seed_prep (host-side pairing and
@@ -652,12 +657,15 @@ class FederatedTrainer:
         cohort = None
         pool_params = pool_gout = None
         if D < D_pool:
-            cohort = sampler.cohort(fc.seed, p, D_pool)
-            jdx = jnp.asarray(cohort)
-            pool_params, pool_gout = dev_params, dev_gout
-            dev_params = jax.tree.map(lambda a: a[jdx], dev_params)
-            dev_gout = dev_gout[jdx]
-            dev_x, dev_y = dev_x[jdx], dev_y[jdx]
+            with jax.profiler.TraceAnnotation("cohort_io", round=p) as span:
+                cohort = sampler.cohort(fc.seed, p, D_pool)
+                jdx = jnp.asarray(cohort)
+                pool_params, pool_gout = dev_params, dev_gout
+                dev_params = jax.tree.map(lambda a: a[jdx], dev_params)
+                dev_gout = dev_gout[jdx]
+                dev_x, dev_y = dev_x[jdx], dev_y[jdx]
+                span.set_metadata(
+                    bytes=tree_nbytes(dev_params, dev_gout, dev_x, dev_y))
         # a caller-supplied plan sized for a different cohort (churn on
         # top of sampling) is rebuilt for this round's link count — and
         # any prefetched draw against the old plan with it
@@ -666,33 +674,34 @@ class FederatedTrainer:
             _pending_link = None
 
         # ---- local updates (eq. 1 / 3) ----
-        dkeys = jax.random.split(jax.random.fold_in(kr, 1), D)
-        if self._arch_trains is None:
-            dev_params, favg, cnt, mloss = self._local_train(
-                dev_params, dev_x, dev_y, dkeys, dev_gout,
-                jnp.asarray(use_kd))
-        else:
-            # per-architecture groups train in their own parameter
-            # spaces; the (D, C, C) output tables reassemble in the
-            # shared output space for the eq. (2) merge below.  Each
-            # device consumes the same dkeys[d] it would draw in a
-            # homogeneous cohort.
-            C = fc.num_classes
-            favg = jnp.zeros((D, C, C))
-            cnt = jnp.zeros((D, C))
-            mloss = jnp.zeros((D,))
-            new_dp = {}
-            for arch, idx, lt in self._arch_trains:
-                ji = jnp.asarray(idx)
-                p_a, f_a, c_a, l_a = lt(
-                    dev_params[arch], dev_x[ji], dev_y[ji], dkeys[ji],
-                    dev_gout[ji], jnp.asarray(use_kd))
-                new_dp[arch] = p_a
-                favg = favg.at[ji].set(f_a)
-                cnt = cnt.at[ji].set(c_a)
-                mloss = mloss.at[ji].set(l_a)
-            dev_params = new_dp
-        jax.block_until_ready(favg)
+        with jax.profiler.TraceAnnotation("local_train", round=p):
+            dkeys = jax.random.split(jax.random.fold_in(kr, 1), D)
+            if self._arch_trains is None:
+                dev_params, favg, cnt, mloss = self._local_train(
+                    dev_params, dev_x, dev_y, dkeys, dev_gout,
+                    jnp.asarray(use_kd))
+            else:
+                # per-architecture groups train in their own parameter
+                # spaces; the (D, C, C) output tables reassemble in the
+                # shared output space for the eq. (2) merge below.  Each
+                # device consumes the same dkeys[d] it would draw in a
+                # homogeneous cohort.
+                C = fc.num_classes
+                favg = jnp.zeros((D, C, C))
+                cnt = jnp.zeros((D, C))
+                mloss = jnp.zeros((D,))
+                new_dp = {}
+                for arch, idx, lt in self._arch_trains:
+                    ji = jnp.asarray(idx)
+                    p_a, f_a, c_a, l_a = lt(
+                        dev_params[arch], dev_x[ji], dev_y[ji], dkeys[ji],
+                        dev_gout[ji], jnp.asarray(use_kd))
+                    new_dp[arch] = p_a
+                    favg = favg.at[ji].set(f_a)
+                    cnt = cnt.at[ji].set(c_a)
+                    mloss = mloss.at[ji].set(l_a)
+                dev_params = new_dp
+            jax.block_until_ready(favg)
 
         # ---- seed collection (first round, FLD family) ----
         if p == 1 and proto in FLD_FAMILY:
@@ -702,34 +711,37 @@ class FederatedTrainer:
         # ---- link pipeline: encode -> channel -> decode ----
         # (collect the prefetched draw when the async program dispatched
         # one — same key, same plan, so bitwise the same outcome)
-        if _pending_link is not None:
-            link = plan.collect(_pending_link)
-        else:
-            link = plan.draw(jax.random.fold_in(kr, 3),
-                             first_round=p == 1)
+        with jax.profiler.TraceAnnotation("link_draw", round=p,
+                                          links=plan.n_links):
+            if _pending_link is not None:
+                link = plan.collect(_pending_link)
+            else:
+                link = plan.draw(jax.random.fold_in(kr, 3),
+                                 first_round=p == 1)
         up_ok = link["up_ok"]
         dn_ok = link["dn_ok"]
-        w = up_ok.astype(np.float32) * dev_x.shape[1]  # |S_d| weights
-        # uplink codec: what the server receives (identity passes the
-        # arrays through untouched; stochastic codecs draw from the
-        # dedicated fold_in(kr, 5) stream, leaving every pre-existing
-        # PRNG consumer bit-identical)
-        dev_params_rx, favg_rx = self._uplink_stage(
-            dev_params, favg, jax.random.fold_in(kr, 5), dev_gout,
-            g_params)
 
         # ---- aggregation + (FLD) conversion ----
-        if proto == "fl":
-            if up_ok.any():
-                g_params = self._weighted_avg(dev_params_rx,
-                                              jnp.asarray(w))
-        else:
-            if up_ok.any():
+        with jax.profiler.TraceAnnotation("aggregate", round=p):
+            w = up_ok.astype(np.float32) * dev_x.shape[1]  # |S_d| weights
+            # uplink codec: what the server receives (identity passes the
+            # arrays through untouched; stochastic codecs draw from the
+            # dedicated fold_in(kr, 5) stream, leaving every pre-existing
+            # PRNG consumer bit-identical)
+            dev_params_rx, favg_rx = self._uplink_stage(
+                dev_params, favg, jax.random.fold_in(kr, 5), dev_gout,
+                g_params)
+            if proto == "fl":
+                if up_ok.any():
+                    g_params = self._weighted_avg(dev_params_rx,
+                                                  jnp.asarray(w))
+            elif up_ok.any():
                 # eq. 2 averaged over the successful device set (psum
                 # collective on the sharded path)
                 gout = self._gout_update(
                     favg_rx, cnt, jnp.asarray(up_ok, jnp.float32))
-            if proto != "fd":
+        if proto in FLD_FAMILY:
+            with jax.profiler.TraceAnnotation("convert", round=p):
                 g_params, _ = output_to_model(
                     self.model.apply, g_params, seeds["train_x"],
                     seeds["train_y"], gout, fc.server_iters,
@@ -737,30 +749,34 @@ class FederatedTrainer:
                     jax.random.fold_in(kr, 4))
 
         # ---- downlink stage (gated per device by dn_ok) ----
-        mask = jnp.asarray(dn_ok)
-        dev_gout = downlink_gout(dev_gout, gout, mask)
-        if proto != "fd":
-            if self._arch_groups is None:
-                dev_params = downlink_params(dev_params, g_params, mask)
-            else:
-                # the converted global model lives in the server
-                # architecture's parameter space: only that group can
-                # receive it; other architectures keep training through
-                # the KD tables delivered above
-                srv = fc.server_model()
-                for arch, idx in self._arch_groups:
-                    if arch == srv:
-                        dev_params = dict(dev_params)
-                        dev_params[srv] = downlink_params(
-                            dev_params[srv], g_params,
-                            mask[jnp.asarray(idx)])
+        with jax.profiler.TraceAnnotation("downlink", round=p):
+            mask = jnp.asarray(dn_ok)
+            dev_gout = downlink_gout(dev_gout, gout, mask)
+            if proto != "fd":
+                if self._arch_groups is None:
+                    dev_params = downlink_params(dev_params, g_params, mask)
+                else:
+                    # the converted global model lives in the server
+                    # architecture's parameter space: only that group can
+                    # receive it; other architectures keep training
+                    # through the KD tables delivered above
+                    srv = fc.server_model()
+                    for arch, idx in self._arch_groups:
+                        if arch == srv:
+                            dev_params = dict(dev_params)
+                            dev_params[srv] = downlink_params(
+                                dev_params[srv], g_params,
+                                mask[jnp.asarray(idx)])
 
         # ---- scatter the trained cohort back into the pool ----
         if cohort is not None:
-            dev_params = jax.tree.map(
-                lambda pool, coh: pool.at[jdx].set(coh), pool_params,
-                dev_params)
-            dev_gout = pool_gout.at[jdx].set(dev_gout)
+            with jax.profiler.TraceAnnotation(
+                    "cohort_io", round=p,
+                    bytes=tree_nbytes(dev_params, dev_gout)):
+                dev_params = jax.tree.map(
+                    lambda pool, coh: pool.at[jdx].set(coh), pool_params,
+                    dev_params)
+                dev_gout = pool_gout.at[jdx].set(dev_gout)
 
         compute_s = time.perf_counter() - t0
         cum_time = state.cum_time_s + compute_s + link["latency_s"]
@@ -770,19 +786,22 @@ class FederatedTrainer:
         # just trained and received the downlink, whereas a fixed
         # device 0 sits out most rounds at small sample_ratio and its
         # stale parameters would stall the reported acc ----
-        ref_dev = 0 if cohort is None else int(cohort[0])
-        if self._arch_groups is None:
-            ref = jax.tree.map(lambda dp: dp[ref_dev], dev_params)
-            acc = float(self._accuracy(ref, test_x, test_y))
-        else:
-            # device 0 sits at position 0 of the first (first-appearance
-            # ordered) architecture group; evaluate with its own apply
-            arch0 = self._arch_groups[0][0]
-            ref = jax.tree.map(lambda dp: dp[0], dev_params[arch0])
-            acc = float(self._arch_acc[arch0](ref, test_x, test_y))
+        with jax.profiler.TraceAnnotation("evaluate", round=p):
+            ref_dev = 0 if cohort is None else int(cohort[0])
+            if self._arch_groups is None:
+                ref = jax.tree.map(lambda dp: dp[ref_dev], dev_params)
+                acc = float(self._accuracy(ref, test_x, test_y))
+            else:
+                # device 0 sits at position 0 of the first (first-
+                # appearance ordered) architecture group; evaluate with
+                # its own apply
+                arch0 = self._arch_groups[0][0]
+                ref = jax.tree.map(lambda dp: dp[0], dev_params[arch0])
+                acc = float(self._arch_acc[arch0](ref, test_x, test_y))
+            loss = float(mloss.mean())
         if log:
             log(f"[{proto}] round {p}: acc={acc:.3f} "
-                f"loss={float(mloss.mean()):.3f} up_ok={up_ok.sum()}/{D} "
+                f"loss={loss:.3f} up_ok={up_ok.sum()}/{D} "
                 f"lat={link['latency_s']*1e3:.0f}ms")
 
         # ---- convergence (relative change < eps) ----
@@ -790,29 +809,30 @@ class FederatedTrainer:
         # for FD, the flattened global model otherwise (a Frobenius norm
         # equals the 2-norm of the ravel, so the FD numbers are the ones
         # the pre-factoring loop produced)
-        if proto == "fd":
-            flat = gout.ravel()
-        else:
-            flat = jnp.concatenate([jnp.ravel(x) for x in
-                                    jax.tree.leaves(g_params)])
-        converged_round = state.converged_round
-        if state.prev is not None:
-            rel = float(jnp.linalg.norm(flat - state.prev) /
-                        jnp.maximum(jnp.linalg.norm(state.prev), 1e-12))
-            # a total-outage round leaves the global state untouched, so
-            # rel == 0 means "nothing arrived", not convergence: the
-            # check only counts when at least one uplink decoded (the
-            # grid path's hit mask applies the same gate)
-            if rel < fc.eps and converged_round is None and \
-                    bool(up_ok.any()):
-                converged_round = p
+        with jax.profiler.TraceAnnotation("converge", round=p):
+            if proto == "fd":
+                flat = gout.ravel()
+            else:
+                flat = jnp.concatenate([jnp.ravel(x) for x in
+                                        jax.tree.leaves(g_params)])
+            converged_round = state.converged_round
+            if state.prev is not None:
+                rel = float(jnp.linalg.norm(flat - state.prev) /
+                            jnp.maximum(jnp.linalg.norm(state.prev), 1e-12))
+                # a total-outage round leaves the global state untouched,
+                # so rel == 0 means "nothing arrived", not convergence:
+                # the check only counts when at least one uplink decoded
+                # (the grid path's hit mask applies the same gate)
+                if rel < fc.eps and converged_round is None and \
+                        bool(up_ok.any()):
+                    converged_round = p
 
         new_state = RoundState(round=p, key=state.key, g_params=g_params,
                                dev_params=dev_params, gout=gout,
                                dev_gout=dev_gout, prev=flat,
                                converged_round=converged_round,
                                seeds=seeds, cum_time_s=cum_time)
-        record = {"round": p, "acc": acc, "loss": float(mloss.mean()),
+        record = {"round": p, "acc": acc, "loss": loss,
                   "round_latency_s": link["latency_s"],
                   "compute_s": compute_s, "cum_time_s": cum_time,
                   "uplink_ok": int(up_ok.sum()),
@@ -1060,15 +1080,16 @@ def make_grid_round_step(model_apply, *, protocol: str, num_devices: int,
         # off the (G, D, ...) pool carry ----
         pool_params, pool_gout = state.dev_params, state.dev_gout
         if sampled:
-            chrt = xs["cohort"]                          # (G, Dc) int32
-            take = jax.vmap(lambda a, i: a[i])
-            dev_params = jax.tree.map(lambda a: take(a, chrt),
-                                      pool_params)
-            dev_gout = take(pool_gout, chrt)
-            if per_config_data:
-                dx, dy = take(dev_x, chrt), take(dev_y, chrt)
-            else:
-                dx, dy = dev_x[chrt], dev_y[chrt]        # (G, Dc, n, ...)
+            with jax.named_scope("cohort_io"):
+                chrt = xs["cohort"]                      # (G, Dc) int32
+                take = jax.vmap(lambda a, i: a[i])
+                dev_params = jax.tree.map(lambda a: take(a, chrt),
+                                          pool_params)
+                dev_gout = take(pool_gout, chrt)
+                if per_config_data:
+                    dx, dy = take(dev_x, chrt), take(dev_y, chrt)
+                else:
+                    dx, dy = dev_x[chrt], dev_y[chrt]    # (G, Dc, n, ...)
         else:
             dev_params, dev_gout = pool_params, pool_gout
             dx, dy = dev_x, dev_y
@@ -1162,11 +1183,13 @@ def make_grid_round_step(model_apply, *, protocol: str, num_devices: int,
 
         # ---- scatter the trained cohort back into the pool carry ----
         if sampled:
-            scatter = jax.vmap(lambda pool, i, coh: pool.at[i].set(coh))
-            dev_params = jax.tree.map(
-                lambda pool, coh: scatter(pool, chrt, coh), pool_params,
-                dev_params)
-            dev_gout = scatter(pool_gout, chrt, dev_gout)
+            with jax.named_scope("cohort_io"):
+                scatter = jax.vmap(
+                    lambda pool, i, coh: pool.at[i].set(coh))
+                dev_params = jax.tree.map(
+                    lambda pool, coh: scatter(pool, chrt, coh),
+                    pool_params, dev_params)
+                dev_gout = scatter(pool_gout, chrt, dev_gout)
 
         # ---- evaluation of the round's reference device: pool device 0
         # at full participation, else each config's first cohort device

@@ -53,7 +53,8 @@ import numpy as np
 from repro import checkpoint
 from repro.channel import ChannelConfig
 from repro.core.privacy import GaussianAccountant
-from repro.core.program import LoopRoundProgram, ProgramOptions
+from repro.core.program import (LoopRoundProgram, ProgramOptions,
+                                tree_nbytes)
 from repro.core.protocols import (FederatedConfig, FederatedTrainer,
                                   summarize_seeds)
 from repro.core.sampling import ChurnConfig
@@ -229,24 +230,32 @@ class FederatedService:
         pool_x, pool_y, test_x, test_y = self._data
         state = RoundState.from_mapping(self.state)
         p = state.round + 1
-        idx = self.churn.active_devices(self.fc.seed, p,
-                                        self.fc.num_devices)
-        jdx = jnp.asarray(idx)
-        cohort = state.replace(
-            dev_params=jax.tree.map(lambda a: a[jdx], state.dev_params),
-            dev_gout=state.dev_gout[jdx])
+        with jax.profiler.TraceAnnotation("cohort_io", round=p) as span:
+            idx = self.churn.active_devices(self.fc.seed, p,
+                                            self.fc.num_devices)
+            jdx = jnp.asarray(idx)
+            cohort = state.replace(
+                dev_params=jax.tree.map(lambda a: a[jdx],
+                                        state.dev_params),
+                dev_gout=state.dev_gout[jdx])
+            dev_x, dev_y = pool_x[jdx], pool_y[jdx]
+            span.set_metadata(bytes=tree_nbytes(
+                cohort.dev_params, cohort.dev_gout, dev_x, dev_y))
         plan = self.trainer.link_plan(state.g_params, n_links=len(idx))
         cohort, rec = self._program.step(
-            cohort, {"dev_x": pool_x[jdx], "dev_y": pool_y[jdx],
+            cohort, {"dev_x": dev_x, "dev_y": dev_y,
                      "test_x": test_x, "test_y": test_y, "plan": plan,
                      "log": log})
         # scatter the cohort's device state back into the pool; shared
         # (global) fields carry over wholesale
-        self.state = cohort.replace(
-            dev_params=jax.tree.map(
-                lambda pool, coh: pool.at[jdx].set(coh),
-                state.dev_params, cohort.dev_params),
-            dev_gout=state.dev_gout.at[jdx].set(cohort.dev_gout))
+        with jax.profiler.TraceAnnotation(
+                "cohort_io", round=p,
+                bytes=tree_nbytes(cohort.dev_params, cohort.dev_gout)):
+            self.state = cohort.replace(
+                dev_params=jax.tree.map(
+                    lambda pool, coh: pool.at[jdx].set(coh),
+                    state.dev_params, cohort.dev_params),
+                dev_gout=state.dev_gout.at[jdx].set(cohort.dev_gout))
         # actual participants: the churned cohort, further narrowed by
         # round_once's client sampling when fc.sample_ratio < 1
         # (rec["cohort"] indexes within the churned cohort)
@@ -289,36 +298,37 @@ class FederatedService:
         if not self.ckpt_dir:
             raise RuntimeError("service has no ckpt_dir")
         state = RoundState.from_mapping(self.state)
-        tree = {"key": np.asarray(state.key),
-                "g_params": state.g_params,
-                "dev_params": state.dev_params,
-                "gout": state.gout,
-                "dev_gout": state.dev_gout}
-        if state.prev is not None:
-            tree["prev"] = state.prev
-        if state.seeds is not None:
-            tree["seeds"] = {"train_x": state.seeds["train_x"],
-                             "train_y": state.seeds["train_y"]}
-        if self._seed_meta is None and state.seeds is not None \
-                and "uploaded" in state.seeds:
-            # the full round-1 dict is only in memory on the run that
-            # collected it; its summary rides along in every checkpoint
-            self._seed_meta = summarize_seeds(state.seeds)
-        meta = {"round": state.round,
-                "cum_time_s": state.cum_time_s,
-                "converged_round": state.converged_round,
-                "protocol": self.fc.protocol,
-                "dp_rounds": (self._acct.rounds
-                              if self._acct is not None else 0),
-                # dense per-device participation counts as a flat int
-                # list — compact at pool scale, unlike a str-keyed dict
-                "dp_device_counts": (
-                    self._acct.device_counts.tolist()
-                    if self._acct is not None else None),
-                "seed_meta": self._seed_meta,
-                "history": self._history_meta()}
-        return checkpoint.save(self.ckpt_dir, state.round, tree,
-                               meta=meta, keep=self.keep)
+        with jax.profiler.TraceAnnotation("checkpoint", round=state.round):
+            tree = {"key": np.asarray(state.key),
+                    "g_params": state.g_params,
+                    "dev_params": state.dev_params,
+                    "gout": state.gout,
+                    "dev_gout": state.dev_gout}
+            if state.prev is not None:
+                tree["prev"] = state.prev
+            if state.seeds is not None:
+                tree["seeds"] = {"train_x": state.seeds["train_x"],
+                                 "train_y": state.seeds["train_y"]}
+            if self._seed_meta is None and state.seeds is not None \
+                    and "uploaded" in state.seeds:
+                # the full round-1 dict is only in memory on the run that
+                # collected it; its summary rides along in every checkpoint
+                self._seed_meta = summarize_seeds(state.seeds)
+            meta = {"round": state.round,
+                    "cum_time_s": state.cum_time_s,
+                    "converged_round": state.converged_round,
+                    "protocol": self.fc.protocol,
+                    "dp_rounds": (self._acct.rounds
+                                  if self._acct is not None else 0),
+                    # dense per-device participation counts as a flat int
+                    # list — compact at pool scale, unlike a str-keyed dict
+                    "dp_device_counts": (
+                        self._acct.device_counts.tolist()
+                        if self._acct is not None else None),
+                    "seed_meta": self._seed_meta,
+                    "history": self._history_meta()}
+            return checkpoint.save(self.ckpt_dir, state.round, tree,
+                                   meta=meta, keep=self.keep)
 
     def restore(self, step: Optional[int] = None) -> int:
         """Rebuild the resumable state from the newest (or ``step``-th)

@@ -669,13 +669,15 @@ class SweepRunner:
         dp = [None] * G
         t0 = time.perf_counter()
         for proto, idxs, prog in self._programs:
-            state, out = prog.run()
             rows = np.asarray(idxs)
+            with jax.profiler.TraceAnnotation("sweep_group",
+                                              points=len(idxs), rounds=R):
+                state, out = prog.run()
+                converged[rows] = np.asarray(state["converged"])
             acc[rows] = out["acc"].T
             loss[rows] = out["loss"].T
             latency[rows] = out["latency_s"].T.astype(np.float64)
             up_ok[rows] = out["up_ok"].T
-            converged[rows] = np.asarray(state["converged"])
             up_bits_first[rows] = prog.up_bits_first
             up_bits[rows] = prog.up_bits_steady
             dp_epsilon[rows] = prog.dp_epsilon
