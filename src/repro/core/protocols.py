@@ -361,21 +361,68 @@ def make_local_train(apply_fn, num_classes: int, local_iters: int,
     return local_train
 
 
+@jax.tree_util.register_pytree_node_class
+class _DeviceRows:
+    """One model's view of shared (D, n, ...) device data: indexing it by
+    sample positions gathers rows ``(dev, idx)`` straight from the shared
+    array, so models vmapped over a (point, device) axis read their
+    device's shard without a per-point copy of the data."""
+
+    def __init__(self, rows, dev):
+        self.rows, self.dev = rows, dev
+
+    def __getitem__(self, idx):
+        return self.rows[self.dev, idx]
+
+    def tree_flatten(self):
+        return (self.rows, self.dev), None
+
+    @classmethod
+    def tree_unflatten(cls, _, leaves):
+        return cls(*leaves)
+
+
 def make_grid_local_train(apply_fn, num_classes: int, local_iters: int,
                           local_batch: int, per_config_data: bool = False):
-    """:func:`make_local_train` double-vmapped for a config grid:
-    operates on (G, D, ...) device state with shared (D, ...) data — or,
-    with ``per_config_data``, per-config (G, D, ...) data (heterogeneous
-    partition grids; ragged ``n_local`` zero-padded to the grid maximum
-    and masked by the per-config ``n_loc`` draw bound) — and per-config
-    (G,) eta/beta/n_loc.  The sweep engine wraps this in shard_map for
-    ``shard_devices`` grids; keeping the vmap chain here means the
-    in_axes stay in one place."""
+    """:func:`make_local_train` for a config grid: operates on (G, D, ...)
+    device state with shared (D, ...) data — or, with
+    ``per_config_data``, per-config (G, D, ...) data (heterogeneous
+    partition grids and sampled cohorts; ragged ``n_local`` zero-padded
+    to the grid maximum and masked by the per-config ``n_loc`` draw
+    bound) — and per-config (G,) eta/beta/n_loc.
+
+    The grid's G x D models are flattened into ONE vmap axis of G·D
+    models, as the trainer vmaps its D devices: a vmap of a vmap gives
+    every activation two batch dimensions, and the TPU lays the rank-6
+    pooling and its gradient out differently from each other, with
+    layout copies between them, where the rank-5 form keeps one layout.
+    Shared data is not copied per point: flat model ``m`` gathers its
+    batch from device ``m % D``'s rows (:class:`_DeviceRows`).  The sweep
+    engine wraps this in shard_map for ``shard_devices`` grids, where
+    each shard flattens its own (G_l, D_l) block."""
     base = make_local_train(apply_fn, num_classes, local_iters, local_batch)
-    per_dev = jax.vmap(base, in_axes=(0, 0, 0, 0, 0, None, None, None, None))
-    dx = 0 if per_config_data else None
-    return jax.vmap(per_dev,
-                    in_axes=(0, dx, dx, 0, 0, None, 0, 0, 0))
+    data_axes = 0 if per_config_data else _DeviceRows(None, 0)
+    flat_train = jax.vmap(base, in_axes=(0, data_axes, data_axes, 0, 0,
+                                         None, 0, 0, 0))
+
+    def grid_local_train(params, x, y, keys, gout, use_kd, eta, beta,
+                         n_loc):
+        G, D = gout.shape[:2]
+
+        def flat(a):
+            return a.reshape((G * D,) + a.shape[2:])
+
+        if per_config_data:
+            x, y = flat(x), flat(y)
+        else:
+            dev = jnp.tile(jnp.arange(D), G)
+            x, y = _DeviceRows(x, dev), _DeviceRows(y, dev)
+        out = flat_train(jax.tree.map(flat, params), x, y, flat(keys),
+                         flat(gout), use_kd, jnp.repeat(eta, D),
+                         jnp.repeat(beta, D), jnp.repeat(n_loc, D))
+        return jax.tree.map(lambda a: a.reshape((G, D) + a.shape[1:]), out)
+
+    return grid_local_train
 
 
 def weighted_avg(stacked, weights):
